@@ -12,12 +12,15 @@ import jax
 import numpy as np
 
 from repro.core import BoltSystem
+from repro.launch.cache import enable_compile_cache
 from repro.models.config import ModelConfig
 from repro.models.lm import init_params
 from repro.serve import (ModelDraft, ModelTarget, ServeEngine,
                          SpeculativeDecoder, decode_response,
                          sequential_decode)
 from repro.streams import Producer, Topic
+
+enable_compile_cache()
 
 cfg = ModelConfig(name="serve-demo", n_layers=2, d_model=64, n_heads=2,
                   n_kv_heads=1, d_ff=128, vocab_size=256,

@@ -27,8 +27,7 @@ def use_mesh(mesh: Optional[jax.sharding.Mesh]):
     _ACTIVE_MESH = mesh
     try:
         if mesh is not None:
-            from ..launch.mesh import activate_mesh
-            with activate_mesh(mesh):
+            with jax.set_mesh(mesh):
                 yield mesh
         else:
             yield None

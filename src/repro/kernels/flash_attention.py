@@ -15,11 +15,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -43,15 +45,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(jnp.isfinite(m_new)[:, None], p, 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_new), 0.0)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
+            # finite mask: a row whose every key in this block is masked gets
+            # a finite m_new, and its bogus p = 1 terms are zeroed by the
+            # correction exp(MASK - m) once the row meets its real keys
+            s = jnp.where(q_pos >= k_pos, s, MASK_VALUE)
+        m_prev = m_ref[...]                                  # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)                       # 0 on first block
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
         v = v_ref[0].astype(jnp.float32)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                               preferred_element_type=jnp.float32))
         m_ref[...] = m_new
@@ -65,7 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(kj == n_kv_blocks - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -104,8 +108,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, block_q, Dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, Dh), q.dtype),
         scratch_shapes=[               # VMEM state carried across kv steps
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),    # m: running row max
+            pltpu.VMEM((block_q, 1), jnp.float32),    # l: running row sum
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
         interpret=interpret,

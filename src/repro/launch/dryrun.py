@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run (assignment deliverable e).
 
 Lowers + compiles every (architecture × shape) cell against the production
@@ -17,24 +13,25 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch all --shape all --out results/
 """
 
-import argparse  # noqa: E402
-import json  # noqa: E402
-import time  # noqa: E402
-from functools import partial  # noqa: E402
+import argparse
+import json
+import os
+import time
+from functools import partial
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+import jax
+import jax.numpy as jnp
 
-from ..configs import ARCHS, get_config  # noqa: E402
-from ..distributed.context import use_mesh  # noqa: E402
-from ..distributed.sharding import param_shardings  # noqa: E402
-from ..models.lm import decode_step, forward  # noqa: E402
-from ..train.optimizer import (AdamWConfig, adamw_init,  # noqa: E402
+from ..configs import ARCHS, get_config
+from ..distributed.context import use_mesh
+from ..distributed.sharding import param_shardings
+from ..models.lm import decode_step, forward
+from ..train.optimizer import (AdamWConfig, adamw_init,
                                opt_state_shardings)
-from ..train.step import make_train_step  # noqa: E402
-from .hlo_cost import analyze  # noqa: E402
-from .mesh import make_production_mesh  # noqa: E402
-from .specs import (SHAPES, abstract_caches, abstract_params,  # noqa: E402
+from ..train.step import make_train_step
+from .hlo_cost import analyze
+from .mesh import make_production_mesh
+from .specs import (SHAPES, abstract_caches, abstract_params,
                     batch_specs, cache_shardings, cell_supported, sds_with)
 
 # ---- TPU v5e hardware constants (assignment §ROOFLINE) ----
@@ -176,6 +173,10 @@ def default_accum(arch: str) -> int:
 
 
 def main() -> None:
+    # 512 virtual CPU devices for the production meshes; set here, before the
+    # first JAX backend use, so importing this module changes no environment
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=512").strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

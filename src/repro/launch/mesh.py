@@ -14,29 +14,12 @@ import jax
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    """`jax.make_mesh` with explicit Auto axis types where the installed jax
-    supports them (>=0.5); older versions have Auto-only meshes anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-
-
-def activate_mesh(mesh: jax.sharding.Mesh):
-    """Context manager making `mesh` ambient across jax versions:
-    ``jax.set_mesh`` (>=0.6), ``jax.sharding.use_mesh`` (0.5), or the Mesh
-    object itself (<=0.4, where Mesh is a context manager)."""
-    setter = getattr(jax, "set_mesh", None) or getattr(jax.sharding, "use_mesh", None)
-    return setter(mesh) if setter is not None else mesh
+    """`jax.make_mesh` with every axis explicitly ``Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
-
-
-def make_host_mesh() -> jax.sharding.Mesh:
-    """Whatever devices exist locally (smoke/benchmarks: 1 CPU device)."""
-    n = len(jax.devices())
-    return make_mesh((1, n), ("data", "model"))

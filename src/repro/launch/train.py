@@ -27,6 +27,7 @@ from ..streams import Topic
 from ..train.checkpoint import CheckpointManager
 from ..train.optimizer import AdamWConfig, adamw_init
 from ..train.step import make_train_step
+from .cache import enable_compile_cache
 
 
 def small_config(d_model: int, n_layers: int, vocab: int) -> ModelConfig:
@@ -103,6 +104,7 @@ def run(steps: int = 100, d_model: int = 128, n_layers: int = 4,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--d-model", type=int, default=128)

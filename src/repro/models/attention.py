@@ -2,9 +2,9 @@
 
 TPU-native choices (DESIGN.md §3):
   * train/prefill attention is an online-softmax scan over KV chunks — the
-    XLA-level flash formulation (fp32 accumulators, chunk sized for VMEM); the
-    explicit Pallas kernel (kernels/flash_attention.py) is selected with
-    ``cfg.use_pallas`` on real TPUs.
+    XLA-level flash formulation (fp32 accumulators, chunk sized for VMEM). The
+    Pallas kernel (kernels/flash_attention.py) computes the same thing but is
+    not called from here.
   * decode shards the KV cache's *sequence* dim over the 'model' axis and
     combines per-shard partial attention with a log-sum-exp reduction — flash-
     decoding expressed in pure SPMD (the cross-shard combine lowers to small

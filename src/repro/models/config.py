@@ -84,7 +84,6 @@ class ModelConfig:
     # ---- performance / distribution knobs (hillclimb targets) ----
     attn_chunk: int = 512                   # flash-attention KV chunk
     remat: str = "full"                     # none | dots | full
-    use_pallas: bool = False                # TPU deploy: Pallas kernels
     pad_heads_to: int = 0                   # pad q-heads for TP divisibility
     kv_repeat: int = 1                      # compute-time kv-head replication
                                             # (MaxText-style; exact for TP>KH)

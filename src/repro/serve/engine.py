@@ -19,6 +19,7 @@ is the same step driven end-to-end at host scale. :class:`ModelTarget` /
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -30,6 +31,10 @@ from ..models.config import ModelConfig
 from ..models.lm import decode_step, init_caches
 from ..streams.topics import Consumer, Topic
 from .speculative import encode_eos, encode_token
+
+
+# one jitted step, shared by every engine and adapter (cfg is static)
+_decode_step = jax.jit(decode_step, static_argnums=0)
 
 
 class _JaxStepper:
@@ -44,8 +49,7 @@ class _JaxStepper:
         self.cfg = cfg
         self.params = params
         self.stats = stats
-        self._step = jax.jit(
-            lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
+        self._step = functools.partial(_decode_step, cfg)
 
     def _greedy(self, logits: jax.Array) -> int:
         return int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
@@ -115,8 +119,7 @@ class ServeEngine:
         self.batch_size = batch_size
         self.max_len = max_len
         self.stats: ServeStats = requests.log.system.serve_stats
-        self._step = jax.jit(
-            lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
+        self._step = functools.partial(_decode_step, cfg)
         self.served = 0
 
     def _greedy(self, logits: jax.Array) -> jax.Array:

@@ -67,7 +67,7 @@ def test_grad_of_scan():
 
 
 def test_collectives_counted_with_trips():
-    from repro.launch.mesh import activate_mesh, make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((len(jax.devices()),), ("model",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     n = len(jax.devices())
@@ -79,7 +79,7 @@ def test_collectives_counted_with_trips():
             return cr + jnp.sum(x, axis=1, keepdims=True), None  # all-reduce
         out, _ = jax.lax.scan(body, jnp.zeros((8, 1)), None, length=5)
         return out
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = jax.jit(fn).lower(xs).compile().as_text()
     c = analyze(txt)
     if n > 1:
