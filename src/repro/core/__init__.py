@@ -22,6 +22,8 @@ Layers (bottom-up):
   api         — the agent-session client API (receipts, speculation sessions,
                 tailing subscriptions — DESIGN.md §12) + BoltSystem wiring
   sim         — deterministic DES used by isolation benchmarks
+  tracing     — spans at the layer boundaries (session API, raft, store,
+                serve engine), kept in memory, off until enabled
 """
 
 from .api import (AgileLog, AppendReceipt, BoltSystem, CommitResult,
@@ -39,6 +41,7 @@ from .gc import GarbageCollector, GCConfig, GCStats
 from .linearize import History, LinearizeResult, check_log
 from .objectstore import (FileObjectStore, MemoryObjectStore, ObjectStore,
                           RangedStore, StoreProfile, TieredObjectStore)
+from .tracing import SPAN_NAMES, SpanRecord, Tracer
 
 __all__ = [
     "AgileLog", "AppendReceipt", "BoltSystem", "CommitResult", "Speculation",
@@ -54,4 +57,5 @@ __all__ = [
     "Unavailable", "NoQuorum", "NotLeader", "LeaseExpired", "NoLiveBrokers",
     "StoreFault", "BrokerCrashed", "AmbiguousProposal",
     "RetryBudgetExhausted",
+    "SPAN_NAMES", "SpanRecord", "Tracer",
 ]

@@ -61,6 +61,7 @@ from .objectstore import (FileObjectStore, MemoryObjectStore, ObjectStore,
                           RangedStore, TieredObjectStore)
 from .raft import MetadataService
 from .sim import ServeStats, SpecStats
+from .tracing import OFF, Tracer
 
 
 def _legacy(old: str, new: str) -> None:
@@ -104,19 +105,20 @@ class AppendReceipt:
         """Force resolution: flush the owning broker if still staged, raise
         the deterministic append error if there was one, return self."""
         p = self._pending
-        if not p.done:
-            fleet = p.broker.fleet
-            if fleet is not None:
-                # route through the fleet's retry layer (§15): if the owning
-                # broker crashes mid-flush, failover re-points p.broker at
-                # the adopter and the retry flushes THERE — the receipt
-                # resolves with the surviving positions
-                fleet._retrying(
-                    lambda _a: None if p.done else p.broker.flush())
-            else:
-                p.broker.flush()
-        if p._error is not None:
-            raise p._error
+        fleet = p.broker.fleet
+        with OFF if fleet is None else fleet.tracer.span("log.wait"):
+            if not p.done:
+                if fleet is not None:
+                    # route through the fleet's retry layer (§15): if the
+                    # owning broker crashes mid-flush, failover re-points
+                    # p.broker at the adopter and the retry flushes THERE —
+                    # the receipt resolves with the surviving positions
+                    fleet._retrying(
+                        lambda _a: None if p.done else p.broker.flush())
+                else:
+                    p.broker.flush()
+            if p._error is not None:
+                raise p._error
         return self
 
     def positions(self) -> Optional[List[int]]:
@@ -586,10 +588,12 @@ class BoltSystem:
             raise TypeError(
                 f"tiering requires a TieredObjectStore (two store classes, "
                 f"§14), got {type(store).__name__}")
+        self.tracer = Tracer()                   # spans, off until enabled
         self.store = store
+        self.store.attach_tracer(self.tracer)
         self.metadata = MetadataService(
             n_replicas=n_meta_replicas, snapshot_every=snapshot_every,
-            pipeline_apply=pipeline_apply,
+            pipeline_apply=pipeline_apply, tracer=self.tracer,
             cf_mode=cf_mode, fork_mode=fork_mode, promote_mode=promote_mode,
             view_cache=view_cache)
         self.brokers = [Broker(i, self.store, self.metadata,
@@ -958,14 +962,16 @@ class AgileLog:
         here), at flush in group-commit mode (errors raise at ``wait()``).
         With a fault plane active (§15) transient failures retry under the
         client policy, failing over to a surviving broker if ours crashes."""
-        return AppendReceipt(self.system._retrying(
-            lambda _a: self._b().submit(self.log_id, [record])))
+        with self.system.tracer.span("log.append"):
+            return AppendReceipt(self.system._retrying(
+                lambda _a: self._b().submit(self.log_id, [record])))
 
     def append_batch(self, records: Sequence[bytes]) -> AppendReceipt:
         """Append a batch atomically; one receipt covering every record."""
         recs = list(records)
-        return AppendReceipt(self.system._retrying(
-            lambda _a: self._b().submit(self.log_id, recs)))
+        with self.system.tracer.span("log.append"):
+            return AppendReceipt(self.system._retrying(
+                lambda _a: self._b().submit(self.log_id, recs)))
 
     def flush(self) -> None:
         """Commit this log's staged records (group commit, DESIGN.md §9).

@@ -39,6 +39,7 @@ from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Tuple
 
 from .errors import ObjectMissing
+from .tracing import OFF, Tracer
 
 
 @dataclass(frozen=True)
@@ -86,8 +87,15 @@ class ObjectStore:
     #: PUTs exercise every layer above, deterministically.
     _faults = None
 
+    #: The owning system's span recorder (``BoltSystem.tracer``), attached
+    #: when the system takes the store; a store on its own records nothing.
+    _tracer: Optional[Tracer] = None
+
     def attach_faults(self, plane) -> None:
         self._faults = plane
+
+    def attach_tracer(self, tracer: Tracer) -> None:
+        self._tracer = tracer
 
     def _fault_put(self, key: str, data: bytes) -> bytes:
         """Consult the fault plane before a PUT. Returns the payload to
@@ -118,7 +126,11 @@ class ObjectStore:
             self._faults.on_delete(key)
 
     def put(self, key: str, data: bytes) -> None:
-        raise NotImplementedError
+        """Durably write ``data`` under ``key``: the one PUT path of every
+        backend (the fault plane, then the backend's raw write)."""
+        tracer = self._tracer
+        with OFF if tracer is None else tracer.span("store.put"):
+            self._commit_put(key, self._fault_put(key, data))
 
     def get(self, key: str, offset: int = 0, length: Optional[int] = None) -> bytes:
         raise NotImplementedError
@@ -154,9 +166,6 @@ class MemoryObjectStore(ObjectStore):
             self._objects[key] = bytes(data)
             self.put_count += 1
             self.bytes_written += len(data)
-
-    def put(self, key: str, data: bytes) -> None:
-        self._commit_put(key, self._fault_put(key, data))
 
     def get(self, key: str, offset: int = 0, length: Optional[int] = None) -> bytes:
         self._fault_get(key)
@@ -240,9 +249,6 @@ class TieredObjectStore(ObjectStore):
             self._hot[key] = bytes(data)
             self.put_count += 1
             self.bytes_written += len(data)
-
-    def put(self, key: str, data: bytes) -> None:
-        self._commit_put(key, self._fault_put(key, data))
 
     def get(self, key: str, offset: int = 0, length: Optional[int] = None) -> bytes:
         self._fault_get(key)
@@ -412,9 +418,6 @@ class FileObjectStore(ObjectStore):
         self._fsync_dir(parent)
         self.put_count += 1
         self.bytes_written += len(data)
-
-    def put(self, key: str, data: bytes) -> None:
-        self._commit_put(key, self._fault_put(key, data))
 
     def get(self, key: str, offset: int = 0, length: Optional[int] = None) -> bytes:
         self._fault_get(key)

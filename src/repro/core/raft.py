@@ -41,6 +41,7 @@ from .errors import (AmbiguousProposal, LeaseExpired, NoQuorum, NotLeader,
                      Unavailable)
 from .faults import RetryPolicy, RetryStats, run_with_retries
 from .metadata import MetadataState
+from .tracing import Tracer
 
 
 @dataclass
@@ -262,7 +263,8 @@ class MetadataService:
     """Client-facing façade: propose() commands, query the leader's state."""
 
     def __init__(self, n_replicas: int = 3, snapshot_every: int = 0,
-                 pipeline_apply: bool = True, **state_kwargs) -> None:
+                 pipeline_apply: bool = True, tracer: Optional[Tracer] = None,
+                 **state_kwargs) -> None:
         make_state = lambda: MetadataState(**state_kwargs)  # noqa: E731
         self.replicas = [Replica(i, make_state) for i in range(n_replicas)]
         self.term = 1
@@ -292,6 +294,8 @@ class MetadataService:
                                      # barrier -> NoQuorum -> election ...)
         self.lease_reads = 0         # reads served by the lease fast path (§18)
         self.lease_fallbacks = 0     # reads that took the slow/barrier path
+        # the owning system's span recorder (BoltSystem.tracer)
+        self.tracer = tracer if tracer is not None else Tracer()
         self.replicas[0].is_leader = True
 
     # -- leadership ------------------------------------------------------------
@@ -450,14 +454,16 @@ class MetadataService:
         policy. Without a plane it is the plain synchronous path."""
         if replica_hint is not None and replica_hint != self.leader_id:
             raise NotLeader(f"replica {replica_hint} is not the leader")
-        plane = self.faults
-        if plane is None or not plane.enabled:
-            return self._propose_once(cmd)
-        token = f"t{self._token_seq}"
-        self._token_seq += 1
-        wrapped = ("idem", token, cmd)
-        return run_with_retries(lambda _attempt: self._propose_once(wrapped),
-                                self.retry, plane.rng, stats=self.retry_stats)
+        with self.tracer.span("meta.propose"):
+            plane = self.faults
+            if plane is None or not plane.enabled:
+                return self._propose_once(cmd)
+            token = f"t{self._token_seq}"
+            self._token_seq += 1
+            wrapped = ("idem", token, cmd)
+            return run_with_retries(
+                lambda _attempt: self._propose_once(wrapped),
+                self.retry, plane.rng, stats=self.retry_stats)
 
     def _propose_once(self, cmd: Tuple) -> object:
         plane = self.faults

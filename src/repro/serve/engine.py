@@ -119,6 +119,7 @@ class ServeEngine:
         self.batch_size = batch_size
         self.max_len = max_len
         self.stats: ServeStats = requests.log.system.serve_stats
+        self.tracer = requests.log.system.tracer
         self._step = functools.partial(_decode_step, cfg)
         self.served = 0
 
@@ -129,41 +130,71 @@ class ServeEngine:
         """Serve one batch of requests from the subscription; returns
         #served. Response tokens are appended as ``(id, seq)`` records plus
         an EOS marker per request; the receipt is waited before the request
-        cursor commits (at-least-once across a crash, deduped by key)."""
-        reqs = self.consumer.poll(self.batch_size)
-        if not reqs:
-            return 0
-        B = len(reqs)
-        self.stats.requests += B
-        prompts = [r["prompt"] for r in reqs]
-        plen = max(len(p) for p in prompts)
-        toks = np.full((B, plen), 1, np.int32)
-        for i, p_ in enumerate(prompts):
-            toks[i, plen - len(p_):] = p_   # left-pad
-        tokens = jnp.asarray(toks)
-        caches = init_caches(self.cfg, B, plen + gen_tokens)
-        logits = None
-        for t in range(plen):   # teacher-forced prefill through the decode path
-            logits, caches = self._step(self.params, caches,
-                                        tokens[:, t:t + 1],
-                                        jnp.asarray(t, jnp.int32))
-            self.stats.model_steps += 1
-        outs = [self._greedy(logits)]
-        for t in range(plen, plen + gen_tokens - 1):
-            logits, caches = self._step(self.params, caches,
-                                        outs[-1][:, None],
-                                        jnp.asarray(t, jnp.int32))
-            outs.append(self._greedy(logits))
-            self.stats.model_steps += 1
-        gen = np.asarray(jnp.stack(outs, axis=1))
-        records: List[bytes] = []
-        for i, r in enumerate(reqs):
-            records.extend(encode_token(r["id"], j, int(tok))
-                           for j, tok in enumerate(gen[i]))
-            records.append(encode_eos(r["id"], int(gen.shape[1])))
-        receipt = self.responses.log.append_batch(records)
-        receipt.wait()          # durable before the request cursor moves
-        self.consumer.commit()
+        cursor commits (at-least-once across a crash, deduped by key).
+
+        While the system's tracer is on, a batch that admits requests
+        records a ``serve.batch`` span holding one span per phase, in
+        order: ``serve.poll``, ``serve.prefill``, ``serve.decode``,
+        ``serve.fetch``, ``serve.encode``, ``serve.append``, ``serve.ack``,
+        ``serve.commit``. The tracer follows JAX's profiler: it records,
+        into the profile too, while a profiler session runs."""
+        tracer = self.tracer
+        tracer.follow(jax.profiler.TraceAnnotation.is_enabled(),
+                      jax.profiler.TraceAnnotation)
+        with tracer.span("serve.batch") as batch:
+            with tracer.span("serve.poll"):
+                reqs = self.consumer.poll(self.batch_size)
+            if not reqs:
+                batch.drop()
+                return 0
+            B = len(reqs)
+            self.stats.requests += B
+            prompts = [r["prompt"] for r in reqs]
+            plen = max(len(p) for p in prompts)
+            if batch.on:
+                batch.set(ids=[r["id"] for r in reqs], size=B,
+                          batch_size=self.batch_size)
+            # each position's host time is a lap of its phase's span
+            with tracer.span("serve.prefill") as phase:
+                timing = phase.on
+                toks = np.full((B, plen), 1, np.int32)
+                for i, p_ in enumerate(prompts):
+                    toks[i, plen - len(p_):] = p_   # left-pad
+                tokens = jnp.asarray(toks)
+                caches = init_caches(self.cfg, B, plen + gen_tokens)
+                logits = None
+                for t in range(plen):   # teacher-forced prefill, decode path
+                    logits, caches = self._step(self.params, caches,
+                                                tokens[:, t:t + 1],
+                                                jnp.asarray(t, jnp.int32))
+                    self.stats.model_steps += 1
+                    if timing:
+                        phase.lap(t)
+            with tracer.span("serve.decode") as phase:
+                timing = phase.on
+                outs = [self._greedy(logits)]
+                for t in range(plen, plen + gen_tokens - 1):
+                    logits, caches = self._step(self.params, caches,
+                                                outs[-1][:, None],
+                                                jnp.asarray(t, jnp.int32))
+                    outs.append(self._greedy(logits))
+                    self.stats.model_steps += 1
+                    if timing:
+                        phase.lap(t)
+            with tracer.span("serve.fetch"):
+                gen = np.asarray(jnp.stack(outs, axis=1))
+            with tracer.span("serve.encode"):
+                records: List[bytes] = []
+                for i, r in enumerate(reqs):
+                    records.extend(encode_token(r["id"], j, int(tok))
+                                   for j, tok in enumerate(gen[i]))
+                    records.append(encode_eos(r["id"], int(gen.shape[1])))
+            with tracer.span("serve.append"):
+                receipt = self.responses.log.append_batch(records)
+            with tracer.span("serve.ack"):
+                receipt.wait()      # durable before the request cursor moves
+            with tracer.span("serve.commit"):
+                self.consumer.commit()
         self.stats.tokens_out += int(gen.size)
         self.stats.responses += B
         self.served += B
